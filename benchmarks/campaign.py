@@ -29,6 +29,7 @@ from repro.bench import results
 from repro.campaign import (CampaignStore, Manifest, format_report,
                             load_manifest, pending_cells, plan_cells,
                             render_report, run_campaign)
+from repro.compile_cache import enable_compile_cache
 
 
 def _store_manifest(store: CampaignStore) -> Manifest:
@@ -103,6 +104,7 @@ def main(argv=None) -> int:
             print("error: --manifest is required for a fresh store",
                   file=sys.stderr)
             return 2
+    enable_compile_cache()
     summary = run_campaign(
         manifest, store, workers=args.workers, shard=args.shard,
         max_cells=args.max_cells, chunk=args.chunk,

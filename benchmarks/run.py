@@ -16,6 +16,7 @@ import sys
 import time
 
 from repro.bench import results
+from repro.compile_cache import enable_compile_cache
 
 
 def _jobs():
@@ -108,6 +109,7 @@ def main(argv=None) -> int:
         print("error: --only matched nothing", file=sys.stderr)
         return 2
     to_run = selected or list(jobs)
+    enable_compile_cache()
 
     for name in to_run:
         desc, fn = jobs[name]

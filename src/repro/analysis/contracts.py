@@ -44,10 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import tree_util as jtu
 
-try:                                    # the blessed home since jax 0.4.35
-    from jax.extend import core as jcore
-except ImportError:                     # pragma: no cover
-    from jax import core as jcore
+from jax.extend import core as jcore
 
 from ..core import POLICIES, make_policy
 from ..core.policy import EMPTY, LANE, Request, pallas_mode
@@ -308,8 +305,7 @@ def verify_contracts(specs=None, pallas_modes=(False, "interpret"), K=8,
             findings += check_tier(use_pallas=mode)
             findings += check_fleet(use_pallas=mode)
     if include_x64 and not jax.config.jax_enable_x64:
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             for spec in specs:
                 findings += check_policy(spec, K=K, use_pallas=False)
     return findings

@@ -5,7 +5,9 @@ deterministically-ordered cell list (:func:`plan_cells`), drops every
 cell whose key already sits in the store (completed *or* quarantined —
 that is the whole resume protocol), optionally takes a ``--shard i/n``
 slice for multi-host launches, and runs the remainder either inline or
-across a ``ProcessPoolExecutor`` (spawn context — safe with jax).
+across a ``ProcessPoolExecutor`` (spawn context — safe with jax).  The
+pool is CPU-only: an accelerator belongs to one process, so on a TPU or
+GPU backend the campaign must run inline.
 
 Each cell executes as a single-cell :class:`repro.bench.Sweep` through
 ``run_sweep(stream="auto")``, so out-of-core traces stream off disk
@@ -181,9 +183,11 @@ def run_campaign(manifest: Manifest, store, *, workers: int = 0,
 
     ``workers <= 1`` runs inline (one process, jit caches shared across
     cells); ``workers > 1`` fans cells out over a spawn-context process
-    pool.  ``shard="i/n"`` takes the i-th round-robin slice of the full
-    plan for multi-host launches — every host runs the same command with
-    a different ``i``.  ``max_cells`` bounds how many cells *execute*
+    pool, and raises ``RuntimeError`` unless JAX's backend is ``cpu``
+    (each worker would claim the accelerator the parent holds).
+    ``shard="i/n"`` takes the i-th round-robin slice of the full plan for
+    multi-host launches — every host runs the same command with a
+    different ``i``.  ``max_cells`` bounds how many cells *execute*
     this invocation (the crash-simulation / smoke-test budget hook);
     skipped cells are free.  ``progress`` (e.g. ``print``) receives one
     ticker line per cell with a running ETA.
@@ -200,6 +204,15 @@ def run_campaign(manifest: Manifest, store, *, workers: int = 0,
     else:
         budget = pending
     skipped = len(cells) - len(pending)
+    pool = bool(workers and workers > 1 and len(budget) > 1)
+    if pool:
+        import jax
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"run_campaign(workers={workers}) would start {workers} JAX "
+                f"processes on the {backend!r} backend, where one process "
+                "holds the device; run inline (workers=0)")
     store.journal(event="start", name=manifest.name, shard=shard,
                   workers=workers, planned=len(cells), skipped=skipped,
                   pending=len(pending), budget=len(budget))
@@ -220,7 +233,7 @@ def run_campaign(manifest: Manifest, store, *, workers: int = 0,
                 f"{cell.policy} K={cell.K} s{cell.seed}: {status} "
                 f"[{wall:.1f}s, ETA {_eta(done, len(budget), elapsed)}]")
 
-    if workers and workers > 1 and len(budget) > 1:
+    if pool:
         import concurrent.futures as cf
         import multiprocessing as mp
         ctx = mp.get_context("spawn")

@@ -209,12 +209,23 @@ def _replay_single(policy, reqs, K, observe, collect_info, use_pallas):
 
 @partial(jax.jit,
          static_argnames=("policy", "K", "observe", "collect_info",
-                          "use_pallas"))
-def _replay_batched(policy, reqs, K, observe, collect_info, use_pallas):
-    with pallas_mode(use_pallas):
-        return jax.vmap(
-            lambda r: _scan_replay(policy, r, K, observe, collect_info)[0]
-        )(reqs)
+                          "use_pallas", "mesh", "axis"))
+def _replay_batched(policy, reqs, K, observe, collect_info, use_pallas,
+                    mesh, axis):
+    """Vmapped lanes; with a ``mesh``, each device scans its own block of
+    lanes under ``shard_map`` (a Pallas kernel cannot be partitioned by
+    the compiler, and independent lanes need no collective)."""
+    def lanes(r):
+        with pallas_mode(use_pallas):
+            return jax.vmap(
+                lambda x: _scan_replay(policy, x, K, observe, collect_info)[0]
+            )(r)
+
+    if mesh is None:
+        return lanes(reqs)
+    return jax.shard_map(lanes, mesh=mesh, in_specs=P(axis),
+                         out_specs=P(axis), axis_names={axis},
+                         check_vma=False)(reqs)
 
 
 @partial(jax.jit, static_argnames=("policy", "use_pallas", "observe"),
@@ -293,11 +304,15 @@ class Engine:
             raise ValueError(
                 f"requests must be [T] or [B, T], got shape {reqs.key.shape}")
         mesh = self.mesh if mesh is None else mesh
+        axis = axis or self.axis
         if mesh is not None:
-            sharding = NamedSharding(mesh, P(axis or self.axis, None))
-            reqs = jax.device_put(reqs, sharding)
+            if reqs.key.shape[0] % mesh.shape[axis]:
+                raise ValueError(
+                    f"{reqs.key.shape[0]} lanes do not divide evenly over "
+                    f"the {mesh.shape[axis]}-device {axis!r} mesh axis")
+            reqs = jax.device_put(reqs, NamedSharding(mesh, P(axis, None)))
         return _replay_batched(policy, reqs, K, observe, collect_info,
-                               use_pallas)
+                               use_pallas, mesh, axis)
 
     def replay_tier(self, tier, requests, *, sizes=None, costs=None,
                     observe: bool = False, use_pallas=None):

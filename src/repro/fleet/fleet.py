@@ -69,7 +69,6 @@ from ..core.policy import (EMPTY, Request, lane_pad, normalize_pallas_mode,
                            pallas_mode)
 from ..core.simulator import Metrics, _count_dtype, _ratio
 from ..tier.arbiter import make_arbiter
-from ..train.train_step import _shard_map
 from . import telemetry
 
 __all__ = ["FleetTier", "FleetResult", "replay_fleet"]
@@ -470,6 +469,8 @@ def _scan_fleet_sharded(tier: FleetTier, reqs: Request, axis: str,
                        hist=hist, obs=obs)
 
 
+@partial(jax.jit, static_argnames=("tier", "mesh", "axis", "rebalance",
+                                   "observe", "use_pallas"))
 def _replay_fleet_sharded(tier, reqs, mesh, axis, rebalance, observe,
                           use_pallas):
     n_shards = int(mesh.shape[axis])
@@ -495,9 +496,9 @@ def _replay_fleet_sharded(tier, reqs, mesh, axis, rebalance, observe,
         avg_k=lane, alive_frac=lane, hist=P(axis, None),
         obs={"k": P(None, axis), "alive": P(None, axis)} if observe
         else None)
-    fn = _shard_map(shard_fn, mesh, in_specs=(P(None, axis),),
-                    out_specs=out_specs, manual_axes=(axis,))
-    return jax.jit(fn)(reqs)
+    return jax.shard_map(shard_fn, mesh=mesh, in_specs=(P(None, axis),),
+                         out_specs=out_specs, axis_names={axis},
+                         check_vma=False)(reqs)
 
 
 def replay_fleet(tier: FleetTier, requests, *, sizes=None, costs=None,

@@ -60,17 +60,21 @@ pre-update occupant of rank ``src`` (the key shifted off the row on a miss).
 Batching: a vmapped ``fused_policy_step`` does NOT fall back to the default
 pallas batching of the single-lane call — a ``jax.custom_batching.custom_vmap``
 rule swaps in the natively batched kernel, whose grid leads with the lane
-axis (``(B, 2, n_tiles)``) and whose scalar I/O lives in unblocked SMEM
-arrays indexed by ``program_id(0)``.  A second (outer) vmap — the tier's
-seeds × tenants nesting — then hits the standard pallas batching rule on
-the batched kernel, which prepends one more grid dimension; both layers
-are Mosaic-lowerable and bit-identical to the vmapped jnp oracle.
+axis (``(B, 2, n_tiles)``) and whose scalar I/O moves through SMEM in
+per-lane ``(1, 1, n)`` blocks indexed by ``program_id(0)``, so SMEM use
+does not grow with the lane count (whole ``[B, n]`` SMEM arrays pad every
+row to 512 bytes and overflow SMEM from about 1,024 lanes).  A second
+(outer) vmap — the tier's seeds × tenants nesting — then hits the
+standard pallas batching rule on the batched kernel, which prepends one
+more grid dimension; both layers are Mosaic-lowerable and bit-identical
+to the vmapped jnp oracle.
 
 ``interpret``: ``True`` runs the kernel body under the Pallas interpreter
 (any backend — the CPU CI path); ``False`` compiles for real (Mosaic on
 TPU, Triton on GPU); ``None`` resolves per backend via
 :func:`resolve_interpret` (memoized, overridable with the
-``REPRO_PALLAS_INTERPRET`` env knob).
+``REPRO_PALLAS_INTERPRET`` env knob, which may not force the interpreter
+on a TPU).
 """
 from __future__ import annotations
 
@@ -98,7 +102,8 @@ DEFAULT_TILE = 16384
 # forced override for CI: "interpret" (or "1"/"true") forces the
 # interpreter, "compiled" (or "0"/"false") forces real lowering —
 # regardless of what the call site passed.  Empty/"auto" defers to the
-# call site, then to the per-backend default.
+# call site, then to the per-backend default.  Forcing the interpreter on
+# a TPU is an error: it would silently replace the kernel being measured.
 INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
 
 
@@ -117,13 +122,20 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
     Priority: the :data:`INTERPRET_ENV` env knob (a *forced* override, so
     CI can pin one lowering across every call site) > an explicit
     ``True``/``False`` argument > the memoized per-backend default
-    (cpu → interpret; tpu/gpu → compiled).
+    (cpu → interpret; tpu/gpu → compiled).  On a TPU backend an env
+    override that forces the interpreter raises instead of falling back.
 
     >>> resolve_interpret(True), resolve_interpret(False)
     (True, False)
     """
+    backend = jax.default_backend()
     env = os.environ.get(INTERPRET_ENV, "").strip().lower()
     if env in ("1", "true", "interpret"):
+        if backend == "tpu":
+            raise RuntimeError(
+                f"{INTERPRET_ENV}={env!r} forces the Pallas interpreter on a "
+                "TPU backend; unset it (or set 'compiled'/'auto') to run "
+                "the compiled kernel")
         return True
     if env in ("0", "false", "compiled"):
         return False
@@ -132,7 +144,7 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
             f"{INTERPRET_ENV} must be interpret/compiled/auto (or a bool "
             f"spelling), got {env!r}")
     if interpret is None:
-        return _backend_default(jax.default_backend())
+        return _backend_default(backend)
     return bool(interpret)
 
 
@@ -168,18 +180,18 @@ def _tiled_kernel(sc_ref, cache_ref, out_ref, scal_out_ref, s_ref, *,
                   plan, n_sc: int, W: int, tile: int):
     """Grid (B, 2, n_tiles): lane b, phase (0 find / 1 plan+promote), tile j.
 
-    ``sc_ref``/``scal_out_ref`` are whole unblocked SMEM arrays ``[B, 1+n]``
-    / ``[B, n+2]`` (key + control scalars in; new scalars + hit + evicted
-    out), indexed by the lane id.  ``cache_ref``/``out_ref`` see one
-    ``(1, 1, tile)`` VMEM block of the ``[B, 1, W]`` row per grid step.
+    ``sc_ref``/``scal_out_ref`` are lane ``b``'s ``(1, 1, 1+n)`` /
+    ``(1, 1, n+2)`` SMEM blocks (key + control scalars in; new scalars +
+    hit + evicted out).  ``cache_ref``/``out_ref`` see one ``(1, 1, tile)``
+    VMEM block of the ``[B, 1, W]`` row per grid step.
     ``s_ref`` is the SMEM cross-tile scratch (per lane: grid iterations run
     lane-major, so one lane's phases/tiles complete before the next lane's
     begin and the scratch never interleaves)."""
-    b, ph, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    ph, j = pl.program_id(1), pl.program_id(2)
     base = j * tile
     r = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1) + base
     row = cache_ref[0]                       # (1, tile) int32
-    key = sc_ref[b, 0]
+    key = sc_ref[0, 0, 0]
 
     @pl.when((ph == 0) & (j == 0))
     def _init_find():
@@ -201,7 +213,7 @@ def _tiled_kernel(sc_ref, cache_ref, out_ref, scal_out_ref, s_ref, *,
         m = s_ref[_S_ARGMIN]
         hit = m < W
         i = jnp.where(hit, m, 0)             # match find()'s argmax-on-miss
-        scalars = tuple(sc_ref[b, 1 + q] for q in range(n_sc))
+        scalars = tuple(sc_ref[0, 0, 1 + q] for q in range(n_sc))
         src, t, wipe_from, new_sc = plan(hit, i, scalars)
         # EMPTY (-1) is created inline: a closure-captured device constant
         # would be rejected by the kernel tracer
@@ -211,8 +223,8 @@ def _tiled_kernel(sc_ref, cache_ref, out_ref, scal_out_ref, s_ref, *,
         s_ref[_S_T] = t                      # the shifted range)
         s_ref[_S_WIPE] = wipe_from
         for q, v in enumerate(new_sc):
-            scal_out_ref[b, q] = v
-        scal_out_ref[b, n_sc] = hit.astype(jnp.int32)
+            scal_out_ref[0, 0, q] = v
+        scal_out_ref[0, 0, n_sc] = hit.astype(jnp.int32)
 
     @pl.when(ph == 1)
     def _promote():
@@ -221,7 +233,7 @@ def _tiled_kernel(sc_ref, cache_ref, out_ref, scal_out_ref, s_ref, *,
         # evicted occupant of rank src: exactly one tile owns it
         @pl.when((src >= base) & (src < base + tile))
         def _evicted():
-            scal_out_ref[b, n_sc + 1] = _split16_pick(row, r == src)
+            scal_out_ref[0, 0, n_sc + 1] = _split16_pick(row, r == src)
         # segmented shift-right-by-one: boundary element comes from the
         # previous tile via the SMEM carry
         rolled = jnp.concatenate(
@@ -243,25 +255,31 @@ def _batched_call(cache, keys, scalars, *, plan, n_sc: int, interpret: bool,
     t = _resolve_tile(W, tile)
     kernel = functools.partial(_tiled_kernel, plan=plan, n_sc=n_sc, W=W,
                                tile=t)
-    sc = jnp.stack([keys] + list(scalars), axis=-1)      # [B, 1+n] SMEM
+    sc = jnp.stack([keys] + list(scalars), axis=-1)[:, None]   # [B, 1, 1+n]
+
+    def lane_smem(n):
+        return pl.BlockSpec((1, 1, n), lambda b, ph, j: (b, 0, 0),
+                            memory_space=pltpu.SMEM)
+
     out, scal = pl.pallas_call(
         kernel,
         grid=(B, 2, W // t),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            lane_smem(1 + n_sc),
             pl.BlockSpec((1, 1, t), lambda b, ph, j: (b, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, t), lambda b, ph, j: (b, 0, j)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            lane_smem(n_sc + 2),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, 1, W), jnp.int32),
-            jax.ShapeDtypeStruct((B, n_sc + 2), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, n_sc + 2), jnp.int32),
         ],
         scratch_shapes=[pltpu.SMEM((_N_SCRATCH,), jnp.int32)],
         interpret=interpret,
     )(sc, cache[:, None, :])
+    scal = scal[:, 0]
     return (out[:, 0], tuple(scal[:, q] for q in range(n_sc)),
             scal[:, n_sc].astype(bool), scal[:, n_sc + 1])
 

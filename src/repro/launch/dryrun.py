@@ -231,7 +231,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_dir: str,
         t_compile = time.perf_counter() - t0 - t_lower
 
         mem = compiled.memory_analysis()
-        cost = compiled.cost_analysis() or {}
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         ana = roofline.analyze_hlo(hlo, default_group=n_chips)
         terms = ana["terms"]

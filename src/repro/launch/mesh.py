@@ -9,12 +9,16 @@ Axes:
 
 Functions, not module constants: importing this module never touches jax
 device state (the dry-run must set XLA_FLAGS before first jax init).
+
+Every axis is ``AxisType.Auto``: the model places activations with
+``with_sharding_constraint`` and leaves the collectives to the compiler,
+which explicit axes (``jax.make_mesh``'s default) do not allow.
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.models.sharding import ShardCtx
 
@@ -24,10 +28,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     need = int(np.prod(shape))
     devs = jax.devices()
-    if len(devs) == need:
-        return jax.make_mesh(shape, axes)
-    if len(devs) > need:            # 512 placeholders, single-pod slice
-        return jax.make_mesh(shape, axes, devices=devs[:need])
+    if len(devs) >= need:           # 512 placeholders: single-pod slice
+        return _auto_mesh(shape, axes, devs[:need])
     raise RuntimeError(
         f"need {need} devices for mesh {shape}, have {len(devs)} — run "
         "under XLA_FLAGS=--xla_force_host_platform_device_count=512")
@@ -38,7 +40,12 @@ def make_test_mesh(data: int = 1, model: int = 1, pod: int = 0):
     shape = (pod, data, model) if pod else (data, model)
     axes = ("pod", "data", "model") if pod else ("data", "model")
     need = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:need])
+    return _auto_mesh(shape, axes, jax.devices()[:need])
+
+
+def _auto_mesh(shape, axes, devices):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def shard_ctx(mesh: Mesh) -> ShardCtx:

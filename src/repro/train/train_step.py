@@ -21,21 +21,6 @@ from repro.optim import adamw
 from . import compression
 
 
-def _shard_map(f, mesh, in_specs, out_specs, manual_axes):
-    """Version-portable shard_map: newer jax exposes ``jax.shard_map`` with
-    ``axis_names``/``check_vma``; older releases only have the experimental
-    one with ``auto``/``check_rep``.  ``manual_axes`` is the set of mesh
-    axes handled manually inside ``f`` (the rest stay with the compiler)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual_axes,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - set(manual_axes)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               auto=auto, check_rep=False)
-
-
 def _accumulated_grads(loss_fn, params, batch, n_micro):
     """Mean loss/grads over n_micro microbatches via lax.scan."""
     if n_micro == 1:
@@ -124,11 +109,11 @@ def make_compressed_train_step(cfg, opt_cfg: adamw.AdamWConfig, mesh,
         return params, opt_state, new_ef, {"loss": loss, **stats}
 
     def train_step(params, opt_state, ef, batch):
-        return _shard_map(
+        return jax.shard_map(
             per_pod, mesh=mesh,
             in_specs=(P(), P(), P(pod_axis), P(pod_axis)),
             out_specs=(P(), P(), P(pod_axis), P()),
-            manual_axes={pod_axis},
+            axis_names={pod_axis}, check_vma=False,
         )(params, opt_state, ef, batch)
 
     return train_step

@@ -210,8 +210,7 @@ def test_tier_and_fleet_contracts(use_pallas):
 
 
 def test_x64_subpass_is_clean():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         for spec in ("dynamicadaptiveclimb", "lru", "hyperbolic", "lhd"):
             assert check_policy(spec) == []
 

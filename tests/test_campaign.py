@@ -226,6 +226,24 @@ def test_workers_spawn_pool(tmp_path, corpus_manifest):
     assert len(store.completed()) == 2
 
 
+@pytest.mark.parametrize("backend", ["tpu", "gpu"])
+def test_workers_refuse_pool_off_cpu(tmp_path, corpus_manifest, monkeypatch,
+                                     backend):
+    """On an accelerator the parent holds the device, so a worker pool
+    would fight it for the chip: refuse before any cell runs."""
+    import jax
+    grid = Grid(policies=("fifo", "lru"), K=(32,), seeds=(0,), T=800)
+    m = dataclasses.replace(
+        corpus_manifest, grid=grid,
+        datasets=tuple(d for d in corpus_manifest.datasets
+                       if d.name == "txt"))
+    store = CampaignStore(str(tmp_path / "store"))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with pytest.raises(RuntimeError, match="inline"):
+        run_campaign(m, store, workers=2)
+    assert store.completed() == []
+
+
 # --- winners tie-break / margin + CDF (bench.report satellites) ------------
 
 def _rec(policy, scenario, miss):

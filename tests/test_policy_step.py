@@ -103,6 +103,24 @@ def test_resolve_interpret_env_override(monkeypatch):
     assert resolve_interpret(None) is expect      # per-backend default
 
 
+@pytest.mark.parametrize("spelling", ["interpret", "1", "true"])
+def test_resolve_interpret_refuses_interpreter_on_tpu(monkeypatch, spelling):
+    from repro.kernels.policy_step import INTERPRET_ENV, resolve_interpret
+    monkeypatch.delenv(INTERPRET_ENV, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False       # auto -> compiled on tpu
+    monkeypatch.setenv(INTERPRET_ENV, spelling)
+    with pytest.raises(RuntimeError, match=INTERPRET_ENV):
+        resolve_interpret(None)
+    with pytest.raises(RuntimeError, match=INTERPRET_ENV):
+        resolve_interpret(False)
+    monkeypatch.setenv(INTERPRET_ENV, "compiled")
+    assert resolve_interpret(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    monkeypatch.setenv(INTERPRET_ENV, spelling)
+    assert resolve_interpret(None) is True        # CPU unchanged
+
+
 def test_pallas_flag_is_noop_for_slot_policies():
     trace = zipf_trace(N=128, T=1200, alpha=1.0, seed=6)
     ref = ENGINE.replay("lru", trace, 16)
@@ -208,7 +226,7 @@ def test_replay_stream_accepts_request_and_rejects_extras():
 def test_lru_timestamps_widen_under_x64():
     st32 = LRU().init(4)
     assert st32["t"].dtype == jnp.int32
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         st64 = LRU().init(4)
         assert st64["t"].dtype == jnp.int64
         assert st64["last"].dtype == jnp.int64

@@ -16,10 +16,7 @@ def test_matches_xla_cost_analysis_on_scanfree_graph():
     c = _compile(f, jax.ShapeDtypeStruct((256, 128), jnp.float32),
                  jax.ShapeDtypeStruct((128, 128), jnp.float32))
     ours = R.analyze_hlo(c.as_text())["flops"]
-    ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict], newer dict
-        ca = ca[0]
-    xla = ca["flops"]
+    xla = c.cost_analysis()["flops"]
     assert abs(ours - xla) / xla < 0.01, (ours, xla)
 
 
