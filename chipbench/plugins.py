@@ -1,0 +1,29 @@
+"""Find a cell's parts by name: ``chipbench/<kind>/<name>.py``, loaded by path.
+
+The kinds are ``generators`` (traffic sources, named by a mix's
+``"generator"``), ``entries`` (the program's entry a configuration drives,
+its ``"entry"``), ``reference`` (its plain reference, its ``"reference"``)
+and ``metrics`` (one reader per metric of ``BENCHMARK.json``).  A later
+cell, mix or metric adds a file here; nothing that exists is edited.
+"""
+from __future__ import annotations
+
+import importlib.util
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def load(kind: str, name: str):
+    mod_name = f"chipbench.{kind}.{name}"
+    if mod_name in sys.modules:
+        return sys.modules[mod_name]
+    path = os.path.join(HERE, kind, name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no {kind} named {name!r} ({path})")
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[mod_name] = mod
+    spec.loader.exec_module(mod)
+    return mod
