@@ -41,6 +41,7 @@ the per-backend default" (compiled on tpu/gpu, interpreted elsewhere; see
 """
 from __future__ import annotations
 
+import itertools
 import warnings
 from functools import partial
 from typing import Any, NamedTuple
@@ -50,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from . import tracing
 from .policy import (Policy, Request, StepInfo, normalize_pallas_mode,
                      pallas_mode)
 
@@ -235,7 +237,7 @@ def _replay_chunk(policy, state, reqs, use_pallas, observe):
     totals (plus the chunk's stacked observables under ``observe`` — only
     ever chunk-shaped, summed into time means on the host).  Handles [T]
     and [B, T] chunks (state batched alike)."""
-    with pallas_mode(use_pallas):
+    with jax.named_scope("replay_chunk"), pallas_mode(use_pallas):
         def one(st, r):
             res, st = _scan_replay(policy, r, K=0, observe=observe,
                                    collect_info=False, state=st)
@@ -244,6 +246,16 @@ def _replay_chunk(policy, state, reqs, use_pallas, observe):
         if reqs.key.ndim == 2:
             return jax.vmap(one)(state, reqs)
         return one(state, reqs)
+
+
+def _put_bytes(keys, sizes, costs) -> int:
+    """Bytes ``Request.of(keys, sizes, costs)`` copies to the device: each
+    host column lands as int32 or float32, a ``None`` as one scalar."""
+    if isinstance(keys, Request):
+        return 0
+    return 4 * sum(int(np.size(1 if c is None else c))
+                   for c in (keys, sizes, costs)
+                   if not isinstance(c, jax.Array))
 
 
 class Engine:
@@ -392,6 +404,11 @@ class Engine:
         per-step observables, without ever materializing a ``[T]`` stack.
         For integer observables the two are bit-identical.
 
+        Each chunk's phases are ``engine.*`` spans, and its compiles and
+        bytes put are counted (:mod:`repro.core.tracing`): under
+        ``jax.profiler.trace`` the spans sit beside the device ops, and
+        ``tracing.last_stream()`` gives the call's per-chunk record.
+
         Returns a :class:`ReplayResult` with ``info=None`` and host-side
         metrics.  Unlike :meth:`replay`, streaming does not consult the
         engine's ``mesh`` — chunks run unsharded on the default device;
@@ -409,7 +426,7 @@ class Engine:
                     "iterator input owns its chunking — chunk= is not "
                     "applied to an iterator; size the chunks at the source")
 
-            def coerce(item):
+            def columns(item):
                 # unwrap (keys, sizes, costs) chunk records —
                 # repro.data.ingest.TraceChunk or a plain 3-tuple of
                 # array-or-None columns — instead of letting them stack
@@ -421,11 +438,10 @@ class Engine:
                         and all(x is None or np.ndim(x) > 0
                                 for x in item[1:]):
                     keys, sizes, costs = item
-                    return Request.of(np.asarray(keys), sizes=sizes,
-                                      costs=costs)
-                return Request.of(item)
+                    return np.asarray(keys), sizes, costs
+                return item, None, None
 
-            chunks = (coerce(item) for item in requests)
+            items = requests
             lead = None                        # lane shape learned on entry
         else:                                  # dense host array
             chunk = (1 << 18) if chunk is None else chunk
@@ -452,10 +468,13 @@ class Engine:
             def dense_chunks():
                 for lo in range(0, keys.shape[-1], chunk):
                     hi = min(lo + chunk, keys.shape[-1])
-                    yield Request.of(keys[..., lo:hi], sl(sizes, lo, hi),
-                                     sl(costs, lo, hi))
+                    yield keys[..., lo:hi], sl(sizes, lo, hi), \
+                        sl(costs, lo, hi)
 
-            chunks = dense_chunks()
+            def columns(item):
+                return item
+
+            items = dense_chunks()
 
         def init_state(lead):
             state = policy.init(K)
@@ -465,38 +484,55 @@ class Engine:
                     state)
             return state
 
-        state = None if lead is None else init_state(lead)
-        totals = None if lead is None else np.zeros((6,) + lead, np.float64)
+        state = totals = None
         obs_sums, T_total = None, 0
-        with warnings.catch_warnings():
+        end = object()
+        with tracing.stream() as rec, warnings.catch_warnings():
             # buffer donation is a no-op on some backends (CPU) — harmless
             warnings.filterwarnings(
                 "ignore", message=".*[Dd]onat.*", category=UserWarning)
-            for reqs in chunks:
+            for i in itertools.count():
+                with tracing.span("engine.source", i):
+                    item = next(items, end)
+                if item is end:
+                    break
+                with tracing.span("engine.put", i):
+                    cols = columns(item)
+                    reqs = Request.of(*cols)
+                    rec.add_h2d(_put_bytes(*cols))
                 if reqs.key.ndim not in (1, 2):
                     raise ValueError(
                         f"chunks must be [T] or [B, T], got shape "
                         f"{reqs.key.shape}")
-                if state is None:              # first iterator chunk
+                if state is None:              # first chunk
                     lead = tuple(reqs.key.shape[:-1])
-                    state = init_state(lead)
+                    with tracing.span("engine.init", i):
+                        state = init_state(lead)
                     totals = np.zeros((6,) + lead, np.float64)
                 elif tuple(reqs.key.shape[:-1]) != tuple(lead):
                     raise ValueError(
                         f"chunk lane shape changed mid-stream: "
                         f"{tuple(reqs.key.shape[:-1])} != {tuple(lead)}")
-                state, m, obs = _replay_chunk(policy, state, reqs,
-                                              use_pallas, observe)
-                totals += np.stack(
-                    [np.asarray(f, dtype=np.float64) for f in m])
-                T_total += reqs.key.shape[-1]
-                if obs is not None:
-                    part = {k: np.asarray(v, np.float64).sum(axis=-1)
-                            for k, v in obs.items()}
-                    obs_sums = part if obs_sums is None else {
-                        k: obs_sums[k] + part[k] for k in part}
-        if totals is None:                     # empty iterator
-            totals = np.zeros(6, np.float64)
+                with tracing.span("engine.dispatch", i):
+                    state, m, obs = _replay_chunk(policy, state, reqs,
+                                                  use_pallas, observe)
+                with tracing.span("engine.wait", i):
+                    jax.block_until_ready((m, obs))
+                with tracing.span("engine.fetch", i):
+                    m = [np.asarray(f) for f in m]
+                    if obs is not None:
+                        obs = {k: np.asarray(v) for k, v in obs.items()}
+                with tracing.span("engine.sum", i):
+                    totals += np.stack([f.astype(np.float64) for f in m])
+                    T_total += reqs.key.shape[-1]
+                    if obs is not None:
+                        part = {k: v.astype(np.float64).sum(axis=-1)
+                                for k, v in obs.items()}
+                        obs_sums = part if obs_sums is None else {
+                            k: obs_sums[k] + part[k] for k in part}
+                rec.end_chunk(i)
+        if totals is None:                     # no chunk
+            totals = np.zeros((6,) + tuple(lead or ()), np.float64)
         metrics = Metrics(
             requests=totals[0].astype(np.int64),
             hits=totals[1].astype(np.int64),

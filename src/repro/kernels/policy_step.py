@@ -278,6 +278,7 @@ def _batched_call(cache, keys, scalars, *, plan, n_sc: int, interpret: bool,
         ],
         scratch_shapes=[pltpu.SMEM((_N_SCRATCH,), jnp.int32)],
         interpret=interpret,
+        name="rank_step",
     )(sc, cache[:, None, :])
     scal = scal[:, 0]
     return (out[:, 0], tuple(scal[:, q] for q in range(n_sc)),
