@@ -49,6 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
+
 EMPTY = jnp.int32(-1)  # repolint: waive[empty-sentinel] -- the definition
 
 # TPU vector-lane count: rank rows are padded to a multiple of LANE so the
@@ -231,16 +233,20 @@ def demote(cache: jax.Array, i: jax.Array, t: jax.Array, key: jax.Array):
 
 _PALLAS_STEP = contextvars.ContextVar("repro_use_pallas_step", default=False)
 
-# the three-valued use_pallas knob threaded through every replay entrypoint:
+# the use_pallas knob threaded through every replay entrypoint:
+#   "auto"      — the engine's default: the compiled dense kernel on a TPU
+#                 for rank rows whose 8-lane block fits its VMEM budget
+#                 (repro.kernels.policy_step.dense_lane_block), the jnp
+#                 lowering everywhere else (CPU, GPU, wider rows)
 #   False       — pure-jnp lowering (find + promote as separate jnp ops)
 #   "interpret" — fused Pallas kernel under the Pallas interpreter (any
 #                 backend; the CPU CI path)
 #   "compiled"  — fused Pallas kernel compiled for real (Mosaic on TPU,
 #                 Triton on GPU); fails on CPU, which cannot execute
 #                 compiled Pallas
-#   True        — auto: the kernel with per-backend interpret resolution
+#   True        — the kernel with per-backend interpret resolution
 #                 (see repro.kernels.policy_step.resolve_interpret)
-PALLAS_MODES = (False, True, "interpret", "compiled")
+PALLAS_MODES = (False, True, "interpret", "compiled", "auto")
 
 
 def normalize_pallas_mode(mode):
@@ -252,10 +258,10 @@ def normalize_pallas_mode(mode):
     Traceback (most recent call last):
         ...
     ValueError: use_pallas must be one of (False, True, 'interpret', \
-'compiled'), got 'fast'
+'compiled', 'auto'), got 'fast'
     """
     if isinstance(mode, str):
-        if mode not in ("interpret", "compiled"):
+        if mode not in ("interpret", "compiled", "auto"):
             raise ValueError(
                 f"use_pallas must be one of {PALLAS_MODES}, got {mode!r}")
         return mode
@@ -268,7 +274,8 @@ def pallas_mode(mode):
     the fused Pallas kernel (``repro.kernels.policy_step``) instead of the
     pure-jnp ``find``/``promote`` pair.  ``mode`` is any of
     :data:`PALLAS_MODES` — ``False`` (jnp), ``"interpret"``, ``"compiled"``,
-    or ``True`` (kernel with per-backend interpret resolution).
+    ``True`` (kernel with per-backend interpret resolution), or ``"auto"``
+    (:func:`auto_lowering`, decided per row width where the row is traced).
 
     Engine-internal: the Engine sets it around tracing and threads the mode
     through its jit static args so all lowerings coexist in the cache.
@@ -280,6 +287,22 @@ def pallas_mode(mode):
         yield
     finally:
         _PALLAS_STEP.reset(tok)
+
+
+def auto_lowering(W: int):
+    """What ``use_pallas="auto"`` (the engine's default) runs for a rank
+    row of ``W`` ranks: ``"compiled"`` — the dense kernel — on a TPU
+    backend when eight padded rows fit the kernel's VMEM budget, else
+    ``False`` (jnp), the lowering on CPU and GPU backends and for rows too
+    wide for the budget.
+
+    >>> jax.default_backend() == "tpu" or auto_lowering(4224) is False
+    True
+    """
+    if jax.default_backend() != "tpu":
+        return False
+    from ..kernels.policy_step import dense_lane_block
+    return "compiled" if dense_lane_block(8, lane_pad(W)) else False
 
 
 def rank_step(cache: jax.Array, key: jax.Array, scalars: tuple, plan):
@@ -298,13 +321,17 @@ def rank_step(cache: jax.Array, key: jax.Array, scalars: tuple, plan):
 
     This is the single entrypoint behind which ``find`` + ``promote`` fuse:
     under :func:`pallas_mode` the whole step — compare, iota-min reduce,
-    scalar plan, rolled masked-select shift, wipe — is one tiled Pallas
-    kernel (``repro.kernels.policy_step``): the row streams HBM→VMEM in
-    :data:`LANE`-multiple tiles, so K no longer has to fit one VMEM row.
+    scalar plan, rolled masked-select shift, wipe — is one Pallas kernel
+    (``repro.kernels.policy_step``): a dense pass over blocks of whole
+    rows, updated in place, where a block of rows fits VMEM, else a
+    tiled pass that streams the row in :data:`LANE`-multiple tiles.
     ``"interpret"`` runs the same kernel body under the Pallas interpreter
-    (the CPU fallback); ``"compiled"`` lowers it for real (Mosaic/Triton).
+    (the CPU fallback); ``"compiled"`` lowers it for real (Mosaic/Triton);
+    ``"auto"`` picks per backend and row width (:func:`auto_lowering`).
     Rows of non-padded width are padded with ``EMPTY`` for the kernel and
-    sliced back — bit-identical to the jnp lowering either way.
+    sliced back — bit-identical to the jnp lowering either way.  The
+    lowering traced is noted in the open stream record
+    (:func:`repro.core.tracing.note_rank_row`).
 
     A CLIMB-shaped plan (miss replaces the bottom rank in place):
 
@@ -318,11 +345,14 @@ def rank_step(cache: jax.Array, key: jax.Array, scalars: tuple, plan):
     ([5, 3, 7], False, 9)
     """
     mode = _PALLAS_STEP.get()
+    if mode == "auto":
+        mode = auto_lowering(cache.shape[0])
     if mode:
         from ..kernels.policy_step import fused_policy_step
         interpret = {True: None, "interpret": True, "compiled": False}[mode]
         return fused_policy_step(cache, key, scalars, plan,
                                  interpret=interpret)
+    tracing.note_rank_row("jnp", 0)
     hit, i = find(cache, key)
     src, t, wipe_from, new_scalars = plan(hit, i, scalars)
     evicted = cache[src]

@@ -30,14 +30,17 @@ Two scale paths (the paper's Tables IV/V throughput regime):
   file-backed traces, see ``repro.data.ingest``) and reports time-mean
   policy observables under ``observe=True``.
 
-``use_pallas`` (an ``Engine`` or per-call switch) lowers the rank-policy
-hot path (find + promote) through the fused Pallas policy-step kernel
-(``repro.kernels.policy_step``) instead of plain jnp.  It is three-valued:
-``False`` (plain jnp), ``"interpret"`` (the kernel under the Pallas
-interpreter — runs anywhere, bit-identical to jnp), and ``"compiled"``
-(the real Mosaic/Triton lowering — TPU/GPU).  ``True`` means "kernel with
-the per-backend default" (compiled on tpu/gpu, interpreted elsewhere; see
-``repro.kernels.policy_step.resolve_interpret``).
+``use_pallas`` (an ``Engine`` or per-call switch) chooses how the
+rank-policy hot path (find + promote) is lowered.  The default,
+``"auto"``, decides by backend and row width where the row is traced:
+on a TPU, rank rows whose 8-lane block fits the kernel's VMEM budget take
+the compiled dense Pallas kernel (``repro.kernels.policy_step``), updated
+in place; everything else (CPU, GPU, wider rows) takes plain jnp.  An
+explicit value wins: ``False`` (plain jnp), ``"interpret"`` (the kernel
+under the Pallas interpreter — runs anywhere, bit-identical to jnp),
+``"compiled"`` (the real Mosaic/Triton lowering — TPU/GPU), or ``True``
+("kernel with the per-backend default": compiled on tpu/gpu, interpreted
+elsewhere; see ``repro.kernels.policy_step.resolve_interpret``).
 """
 from __future__ import annotations
 
@@ -248,6 +251,12 @@ def _replay_chunk(policy, state, reqs, use_pallas, observe):
         return one(state, reqs)
 
 
+# rank-row lowering noted when each chunk program was traced, by
+# (policy, use_pallas, observe, K, lane shape): what a call whose program
+# came from the jit cache reports
+_RANK_ROW: dict = {}
+
+
 def _put_bytes(keys, sizes, costs) -> int:
     """Bytes ``Request.of(keys, sizes, costs)`` copies to the device: each
     host column lands as int32 or float32, a ``None`` as one scalar."""
@@ -264,10 +273,12 @@ class Engine:
     replays B/axis_size independent caches, the TPU-native version of the
     paper's multi-threaded trace replay, Tables IV/V).
 
-    ``use_pallas`` routes the rank-policy hot path through the fused Pallas
-    policy-step kernel (overridable per call): ``False`` / ``"interpret"``
-    / ``"compiled"``, or ``True`` for the per-backend default.  Slot-based
-    policies are unaffected by the flag.
+    ``use_pallas`` (overridable per call) lowers the rank-policy hot path:
+    ``"auto"`` (the default) takes the compiled dense Pallas kernel on a
+    TPU for rows that fit its VMEM budget and jnp everywhere else
+    (:func:`repro.core.policy.auto_lowering`); ``False`` / ``"interpret"``
+    / ``"compiled"`` / ``True`` force one lowering.  Slot-based policies
+    are unaffected by the flag.
 
     >>> import numpy as np
     >>> res = Engine().replay("dac", np.zeros((2, 5), np.int32), K=4)
@@ -276,7 +287,7 @@ class Engine:
     """
 
     def __init__(self, mesh=None, axis: str = "data",
-                 use_pallas=False):
+                 use_pallas="auto"):
         self.mesh = mesh
         self.axis = axis
         self.use_pallas = normalize_pallas_mode(use_pallas)
@@ -407,7 +418,8 @@ class Engine:
         Each chunk's phases are ``engine.*`` spans, and its compiles and
         bytes put are counted (:mod:`repro.core.tracing`): under
         ``jax.profiler.trace`` the spans sit beside the device ops, and
-        ``tracing.last_stream()`` gives the call's per-chunk record.
+        ``tracing.last_stream()`` gives the call's per-chunk record and
+        the rank-row lowering its chunk program traced (``rank_row``).
 
         Returns a :class:`ReplayResult` with ``info=None`` and host-side
         metrics.  Unlike :meth:`replay`, streaming does not consult the
@@ -516,6 +528,13 @@ class Engine:
                 with tracing.span("engine.dispatch", i):
                     state, m, obs = _replay_chunk(policy, state, reqs,
                                                   use_pallas, observe)
+                if i == 0:
+                    # a jit-cache hit traces nothing: carry the lowering
+                    # noted when this chunk program was traced
+                    prog = (policy, use_pallas, observe, K, lead)
+                    if rec.rank_row is None:
+                        rec.rank_row = _RANK_ROW.get(prog)
+                    _RANK_ROW[prog] = rec.rank_row
                 with tracing.span("engine.wait", i):
                     jax.block_until_ready((m, obs))
                 with tracing.span("engine.fetch", i):

@@ -77,6 +77,10 @@ class StreamTrace(NamedTuple):
     ``totals`` holds the same columns summed over the whole call (the
     end-of-stream pull included, ``chunk`` left out); ``compiled`` maps a
     compiled or traced function's name to ``(compiles, seconds)``.
+    ``rank_row`` is the rank-row lowering the chunk program traced,
+    ``(lowering, lane_block)`` with lowering ``"dense"``, ``"tiled"`` or
+    ``"jnp"`` (see :func:`note_rank_row`), or ``None`` for a policy with
+    no rank row.
     """
 
     rows: dict
@@ -84,6 +88,7 @@ class StreamTrace(NamedTuple):
     compiled: dict
     chunks: int
     wall_s: float
+    rank_row: tuple | None = None
 
 
 class StreamRecord:
@@ -99,6 +104,7 @@ class StreamRecord:
         self.chunks = 0
         self.wall_ns = 0
         self.open = False          # a span of this record is open
+        self.rank_row = None       # (lowering, lane block), set on trace
 
     def add_h2d(self, nbytes: int) -> None:
         self._row[_H2D] += nbytes
@@ -141,7 +147,8 @@ class StreamRecord:
                   for c, t in zip(COLUMNS[1:], self._totals[1:])}
         return StreamTrace(rows={c: rows[:, j] for j, c in enumerate(COLUMNS)},
                            totals=totals, compiled=dict(self._compiled),
-                           chunks=self.chunks, wall_s=self.wall_ns * 1e-9)
+                           chunks=self.chunks, wall_s=self.wall_ns * 1e-9,
+                           rank_row=self.rank_row)
 
 
 class span:
@@ -186,6 +193,16 @@ def stream():
         _local.rec = prev
     rec.finish(time.perf_counter_ns() - t0)
     _last = rec
+
+
+def note_rank_row(lowering: str, lane_block: int) -> None:
+    """Called while ``rank_step`` is traced: the rank-row ``lowering``
+    (``"dense"``, ``"tiled"`` or ``"jnp"``) and lanes per grid step
+    (``lane_block``; 0 for jnp).  It lands in this thread's open
+    :func:`stream` record, so it costs nothing per chunk."""
+    rec = getattr(_local, "rec", None)
+    if rec is not None:
+        rec.rank_row = (lowering, int(lane_block))
 
 
 def last_stream() -> StreamTrace | None:

@@ -4,13 +4,15 @@
                        prefill compute hot spot)
   * decode_attention — flash-decode over the KV slot table with fused DAC
                        hit-signal (per-slot attention mass) extraction
-  * policy_step      — tiled fused rank-policy step (find + plan + promote
-                       + wipe in one segmented pass over the lane-padded
-                       rank row); serves every rank policy — Climb,
-                       AdaptiveClimb, DAC, the budgeted tier step — via a
-                       traced-in control-law callback and backs the
-                       engine's three-valued ``use_pallas`` replay path
-                       (``False`` / ``"interpret"`` / ``"compiled"``)
+  * policy_step      — fused rank-policy step (find + plan + promote
+                       + wipe): a dense in-place pass over blocks of
+                       whole lane-padded rank rows, or a tiled segmented
+                       pass for rows too wide for VMEM; serves every rank
+                       policy — Climb, AdaptiveClimb, DAC, the budgeted
+                       tier step — via a traced-in control-law callback
+                       and backs the engine's ``use_pallas`` replay path
+                       (``"auto"``, the default: the compiled dense kernel
+                       on a TPU where it fits, jnp elsewhere)
 
 Each has a pure-jnp oracle (ref.py for the attention kernels;
 core.policy.rank_step *is* the oracle for policy_step); ops.py exposes

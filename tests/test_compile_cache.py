@@ -22,7 +22,8 @@ def updates(monkeypatch):
 def test_environment_directory_is_honoured(monkeypatch, updates, tmp_path):
     monkeypatch.setenv(CACHE_ENV, str(tmp_path))
     assert enable_compile_cache() == str(tmp_path)
-    assert updates == []           # JAX reads the variable itself
+    # JAX reads the variable itself; only the threshold is set
+    assert updates == [("jax_persistent_cache_min_compile_time_secs", 0)]
 
 
 @pytest.mark.parametrize("value", [None, ""])
@@ -34,4 +35,5 @@ def test_default_is_the_fixed_checkout_directory(monkeypatch, updates,
         monkeypatch.setenv(CACHE_ENV, value)
     expect = os.path.join(ROOT, ".jax_cache")
     assert enable_compile_cache() == expect
-    assert updates == [("jax_compilation_cache_dir", expect)]
+    assert updates == [("jax_persistent_cache_min_compile_time_secs", 0),
+                       ("jax_compilation_cache_dir", expect)]

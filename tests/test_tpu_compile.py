@@ -11,6 +11,7 @@ module-scoped fixture, never at import, so every test worker collects the
 same tests and only the worker that runs this file takes libtpu's lock.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -22,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import make_policy
 from repro.core.policy import Request
-from repro.core.simulator import _replay_batched, _replay_single
+from repro.core.simulator import _replay_batched, _replay_chunk, _replay_single
 from repro.fleet import FleetTier
 from repro.fleet.fleet import _replay_fleet_sharded, _replay_fleet_single
 from repro.tier import CacheTier
@@ -126,3 +127,23 @@ def test_fleet_step_over_four_chips(topo):
         tier, _requests((T, 64), NamedSharding(mesh, P(None, "data"))),
         mesh, "data", 256, True, "compiled"))
     assert "all-reduce" in c.as_text()         # the psum budget re-deal
+
+
+@pytest.mark.parametrize("lanes", [None, 5, 1024])
+def test_stream_chunk_dense_kernel(one_chip, lanes):
+    """The stream chunk program of the ``dac_paper_s`` cells (DAC at
+    K = 1,048: rows of 4,224 ranks) on the dense kernel: one lane, fewer
+    lanes than a block, and 1,024 lanes, whose carried ``[1024, 4224]``
+    state the kernel updates in place, with no copy of it in the scan."""
+    pol = make_policy("dac(eps=0.5,growth=4,k_min=2)")
+    lead = () if lanes is None else (lanes,)
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(lead + x.shape, x.dtype,
+                                       sharding=one_chip), pol.init(1048))
+    text = _assert_kernel(_replay_chunk.lower(
+        pol, state, _requests(lead + (T,), one_chip), "compiled",
+        False)).as_text()
+    if lanes == 1024:
+        assert "output_to_operand_aliasing={{0}: (1, {})}" in text
+        assert not re.search(
+            r"= \(?s32\[1024,4224\]\S* copy(-start|-done)?\(", text)
