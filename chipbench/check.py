@@ -5,7 +5,9 @@ warm-up and window together) are held against what the harness fed it and
 against the plain reference (``chipbench/reference``) replaying the same
 chunks:
 
-* ``requests_gap``: every lane, requests counted against requests fed;
+* ``requests_gap``: every lane, requests counted against requests fed, as
+  the source counts them (``generator.live``: its ``live`` hook, where its
+  lanes idle, else ``T`` a lane a chunk);
 * ``hits_gap``, ``bytes_missed_gap``, ``bytes_total_gap``: the lanes of a
   sample drawn from the seed, against the reference.
 
@@ -13,14 +15,19 @@ Each number is the largest absolute gap over the lanes it covers.  All
 four are exact: the limit is 0.
 
 The configuration names its reference (``"reference"``), a module under
-``chipbench/reference/`` with ``replay_chunks(chunks, config, key_bits)``.
+``chipbench/reference/`` with ``replay_chunks(chunks, config, key_bits)``,
+which replays one lane alone.  A reference whose lanes share state (a pool:
+admission, arbitration and budgets couple them) defines the hook
+``replay_lanes(source, lanes, n_chunks, config, key_bits)`` instead, which
+replays the source's first ``n_chunks`` chunks once and returns
+``{lane: totals}`` for the lanes asked; the check then calls it once.
 """
 from __future__ import annotations
 
 import numpy as np
 
 from chipbench import plugins
-from chipbench.generator import rng_for
+from chipbench.generator import live, rng_for
 
 LIMITS = {"requests_gap": 0, "hits_gap": 0, "bytes_missed_gap": 0,
           "bytes_total_gap": 0}
@@ -43,9 +50,15 @@ def sample_lanes(lanes: int, n: int, strata: int, seed: int) -> list:
 def reference_totals(config: dict, source, lanes: list, n_chunks: int,
                      key_bits: int = 32) -> dict:
     ref = plugins.load("reference", config["reference"])
-    return {lane: ref.replay_chunks(source.lane_chunks(lane, n_chunks),
-                                    config, key_bits)
-            for lane in lanes}
+    if not hasattr(ref, "replay_lanes"):
+        return {lane: ref.replay_chunks(source.lane_chunks(lane, n_chunks),
+                                        config, key_bits)
+                for lane in lanes}
+    got = ref.replay_lanes(source, lanes, n_chunks, config, key_bits)
+    if sorted(got) != sorted(lanes):
+        raise ValueError(f"replay_lanes gave lanes {sorted(got)}, "
+                         f"{sorted(lanes)} asked")
+    return got
 
 
 def compare(metrics, config: dict, source, n_chunks: int,
@@ -56,7 +69,7 @@ def compare(metrics, config: dict, source, n_chunks: int,
     lane (where the reference reports them)."""
     got = {f: np.atleast_1d(np.asarray(getattr(metrics, f), np.float64))
            for f in ("requests", "hits", "bytes_total", "bytes_missed")}
-    fed = n_chunks * source.T
+    fed = live(source, 0, n_chunks)
     requests_gap = float(np.max(np.abs(got["requests"] - fed)))
     bad = set(np.flatnonzero(got["requests"] != fed).tolist())
     ref = reference_totals(config, source, lanes, n_chunks)

@@ -2,9 +2,16 @@
 
 A traffic mix is a JSON file under ``chipbench/traffic/``; its
 ``"generator"`` key names a source under ``chipbench/generators/`` (loaded
-by path), and the rest are that source's parameters.  A source gives the
-engine one chunk per ``next()`` and can hand the plain reference any
-lane's chunks again afterwards (``lane_chunks``).
+by path), and the rest are that source's parameters.  A source has
+``lanes`` and ``T`` (requests a lane a chunk), gives the engine one chunk
+per ``next()``, and can hand the plain reference any lane's chunks again
+afterwards (``lane_chunks``).
+
+A source whose lanes idle (a pool's lane with no tenant carries key ``-1``
+for that step) defines the hook ``live(lo, hi)``: per lane, the requests
+it handed out in chunks ``[lo, hi)``, idle slots not counted.  The harness
+reads it through :func:`live`, which gives ``T`` a lane a chunk for a
+source without the hook.
 
 The Zipf sampler, the log-normal size model and the oracleGeneral writer
 are copies, so that later changes to the program do not move the
@@ -65,6 +72,14 @@ def write_oracle_general(path, raw_ids: np.ndarray, sizes: np.ndarray):
     rec["size"] = sizes.astype(np.uint32)
     rec["next"] = next_access(raw_ids)
     rec.tofile(path)
+
+
+def live(source, lo: int, hi: int) -> np.ndarray:
+    """Requests per lane (``int64[lanes]``) that ``source`` handed out in
+    chunks ``[lo, hi)``: its ``live`` hook, or ``T`` a lane a chunk."""
+    if hasattr(source, "live"):
+        return np.asarray(source.live(lo, hi), np.int64).reshape(source.lanes)
+    return np.full(source.lanes, (hi - lo) * source.T, np.int64)
 
 
 def build(config: dict, mix: dict, seed: int):

@@ -1,5 +1,6 @@
-"""Device operations that ran in the traced window per scan step: the
-launches that a time loop inside one kernel would save."""
+"""Device operations that ran in the traced window per scan step, on each
+chip (``tracereduce``: averaged over the chips that ran any): the launches
+that a time loop inside one kernel would save."""
 
 
 def read(ctx):

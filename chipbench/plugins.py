@@ -5,6 +5,14 @@ The kinds are ``generators`` (traffic sources, named by a mix's
 its ``"entry"``), ``reference`` (its plain reference, its ``"reference"``)
 and ``metrics`` (one reader per metric of ``BENCHMARK.json``).  A later
 cell, mix or metric adds a file here; nothing that exists is edited.
+
+Two hooks are optional, and the harness behaves as without them where a
+part leaves them out: a source's ``live(lo, hi)``, the requests per lane
+it handed out in chunks ``[lo, hi)`` where its lanes idle
+(``generator.py``), and a reference's ``replay_lanes(source, lanes,
+n_chunks, config, key_bits)``, one replay of lanes that share state
+(``check.py``).  A module already in ``sys.modules`` under
+``chipbench.<kind>.<name>`` is taken as it is (the tests' stand-ins).
 """
 from __future__ import annotations
 

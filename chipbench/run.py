@@ -20,12 +20,18 @@ by name (``plugins.py``).  A run:
    trace (``tracereduce.py``);
 5. compares the engine's totals with what was fed and with the plain
    reference (``check.py``), and prints each number compared beside its
-   limit, last on standard error;
+   limit, last on standard error.  What was fed is what the source
+   counts (its ``live`` hook, where its lanes idle: ``generator.py``); a
+   reference whose lanes share state replays them in one call (its
+   ``replay_lanes`` hook: ``check.py``);
 6. prints one JSON line last on standard output: ``correct``,
-   ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics,
-   or with ``--trace 1`` its per-layer ones), ``device``, ``breakdown``
-   (traced runs) and ``checks``.  A traced run in which a per-layer
-   metric of the cell finds nothing to read fails instead.
+   ``attempted`` (the requests the source handed out in the window, idle
+   slots not counted: the numerator of ``requests_per_s``), ``failed``
+   (those of them on the lanes that failed a check), ``metrics`` (the
+   cell's end-to-end metrics, or with ``--trace 1`` its per-layer ones),
+   ``device``, ``breakdown`` (traced runs) and ``checks``.  A traced run
+   in which a per-layer metric of the cell finds nothing to read fails
+   instead.
 
 JAX's compilation cache is kept in ``.jax_cache`` at the root of the
 checkout (or in ``$JAX_COMPILATION_CACHE_DIR``).
@@ -140,12 +146,13 @@ def run_cell(config: dict, mix: dict, seed: int, seconds: float, trace: bool,
         lanes = check.sample_lanes(source.lanes, mix["check_lanes"],
                                    source.strata, seed)
         verdict = check.compare(res.metrics, config, source, win.fed, lanes)
+        window_live = generator.live(source, win.warmup, win.fed)
     finally:
         source.close()
         if log_dir:
             shutil.rmtree(log_dir, ignore_errors=True)
 
-    window_requests = len(win.calls) * source.lanes * source.T
+    window_requests = int(window_live.sum())
     ctx = {"config": config, "traffic": mix, "lanes": source.lanes,
            "setup_s": win.start - t_process, "window_s": t_end - win.start,
            "window_requests": window_requests, "chunk_s": win.chunk_s,
@@ -161,7 +168,7 @@ def run_cell(config: dict, mix: dict, seed: int, seconds: float, trace: bool,
     out = {
         "correct": correct,
         "attempted": window_requests,
-        "failed": len(failed_lanes) * len(win.calls) * source.T,
+        "failed": int(window_live[failed_lanes].sum()),
         "metrics": values,
         "device": {
             "platform": devices[0].platform if devices else "none",

@@ -14,8 +14,12 @@ All seeds run in one process, through ``run.run_cell``, on the chip:
   the lanes (or, on one lane, half of each chunk) left out and the rest's
   mean put in their place (``half_batch``), and the answer of every
   request to a key divisible by 97 reported as a miss (``altered``).
-  There is no exchange between chips on the timed path, so that fault
-  has no run.
+  There is no exchange between chips on the timed path of the cells so
+  far, so that fault has no run.  A pool cell on four chips (the fleet's
+  ``psum`` re-deal of the slot budget every ``rebalance`` steps) will
+  need one, the exchange left out, which the PR that adds the cell must
+  add here, with a control that replays the pool's coupled lanes together
+  (a reference's ``replay_lanes``, ``check.py``).
 
 ``tests/test_selfcheck.py`` makes the same runs at a size a test can hold,
 on the CPU, with the look for a chip skipped.

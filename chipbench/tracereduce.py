@@ -8,8 +8,9 @@ by name, so that renaming a jitted function or a kernel moves nothing:
 * ``busy_s``: the union of the intervals in which an operation ran on the
   device, inside the window, averaged over the devices that ran any (a
   trace in which none ran is an error, not a zero);
-* ``ops``: the device operations that started inside the window (summed
-  over devices);
+* ``ops``: the device operations that started inside the window, per
+  chip: averaged over the devices that ran any, as ``busy_s`` is, so that
+  a step on four chips reads what one chip ran (one chip: their count);
 * ``device_ops``: the ten operation names that took the most device time;
 * ``idle_gaps``: the idle time inside the window, by what the host was
   doing: ``feed`` (the harness's chunk source), ``in_program`` (a device
@@ -92,9 +93,13 @@ def load(path):
 
 
 def reduce_trace(path) -> dict:
-    host, devices = load(path)
+    return reduce_planes(*load(path), where=str(path))
+
+
+def reduce_planes(host: dict, devices: list, where: str = "trace") -> dict:
+    """The numbers above from :func:`load`'s host spans and devices."""
     if "traced_window" not in host or len(host["traced_window"]) != 1:
-        raise ValueError(f"{path}: no single traced_window span")
+        raise ValueError(f"{where}: no single traced_window span")
     lo, hi = host["traced_window"][0]
     feed = _union(_clip(host.get("feed", np.zeros((0, 2))), lo, hi))
     busy, n_ops = [], 0
@@ -123,7 +128,7 @@ def reduce_trace(path) -> dict:
         gaps["replay_stream"] += float(length[~in_prog & ~in_feed].sum())
     if not busy:
         raise ValueError(
-            f"{path}: no device operation inside traced_window "
+            f"{where}: no device operation inside traced_window "
             f"[{lo:.0f}, {hi:.0f}] ns; device planes with an {OPS_LINE!r} "
             f"line and their op spans: "
             f"{[(d['plane'], d['ops'][:, 0].min(), d['ops'][:, 1].max()) for d in devices if len(d['ops'])]}")
@@ -132,7 +137,7 @@ def reduce_trace(path) -> dict:
         "window_s": (hi - lo) * 1e-9,
         "busy_s": sum(busy) / n_dev,
         "devices": len(busy),
-        "ops": n_ops,
+        "ops": n_ops / n_dev,
         "feed_s": float((feed[:, 1] - feed[:, 0]).sum()) * 1e-9,
         "device_ops": [[n, t / n_dev] for n, t in by_name.most_common(10)],
         "idle_gaps": [[n, t / n_dev] for n, t in gaps.most_common(10)],
