@@ -64,3 +64,9 @@ class OracleFile:
 
 def make(config: dict, mix: dict, seed: int) -> OracleFile:
     return OracleFile(config, mix, seed)
+
+
+def shrink(config: dict, mix: dict) -> tuple:
+    """The same code paths and key space at a size a CPU test holds: a
+    shorter file, small caches."""
+    return dict(config, K=512), dict(mix, requests=1 << 18)

@@ -66,3 +66,10 @@ class LaneRing:
 
 def make(config: dict, mix: dict, seed: int) -> LaneRing:
     return LaneRing(config, mix, seed)
+
+
+def shrink(config: dict, mix: dict) -> tuple:
+    """The same code paths and key space at a size a CPU test holds: two
+    lanes of each exponent, short chunks and rings, small caches."""
+    return (dict(config, K=512),
+            dict(mix, chunk=[2 * len(mix["alphas"]), 16], ring=4096))

@@ -15,7 +15,10 @@ by name (``plugins.py``).  A run:
 3. drives the configuration's entry once (``Engine().replay_stream``,
    as a user calls it, with no lowering chosen) over ``window.Window``:
    warm-up chunks (set-up: the compile or cache load, and the caches
-   filled to their steady size), then ``--seconds`` of whole chunks;
+   filled to their steady size), then ``--seconds`` of whole chunks.  An
+   entry whose ``drive`` declares a ``devices`` parameter is handed the
+   chips the cell holds there (to build its mesh on); any other is called
+   without it;
 4. with ``--trace 1``, profiles a few chunks of the window and reduces the
    trace (``tracereduce.py``);
 5. compares the engine's totals with what was fed and with the plain
@@ -44,6 +47,7 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import glob  # noqa: E402
+import inspect  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -117,10 +121,14 @@ def run_cell(config: dict, mix: dict, seed: int, seconds: float, trace: bool,
              metrics: list, *, devices, engine=None, peak=None,
              t_process: float = T_PROCESS) -> dict:
     """Drive one run and return its result line (a dict).  ``devices``
-    are the chips the cell holds (``[]`` skips the device readings);
-    ``engine`` stands in for the program's own (controls, faults)."""
+    are the chips the cell holds (``[]`` skips the device readings), handed
+    on to an entry whose ``drive`` declares ``devices``; ``engine`` stands
+    in for the program's own (controls, faults)."""
     enable_compile_cache()
     entry = plugins.load("entries", config["entry"])
+    chips = ({"devices": devices}
+             if "devices" in inspect.signature(entry.drive).parameters
+             else {})
     source = generator.build(config, mix, seed)
     log_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
     tracer = (Tracer(log_dir, mix["trace_skip"], mix["trace_chunks"])
@@ -129,7 +137,7 @@ def run_cell(config: dict, mix: dict, seed: int, seconds: float, trace: bool,
     try:
         try:
             with jax.profiler.TraceAnnotation("replay_stream"):
-                res = entry.drive(config, win, engine)
+                res = entry.drive(config, win, engine, **chips)
             t_end = time.perf_counter()
         finally:
             if tracer is not None:

@@ -9,12 +9,14 @@ def test_every_named_part_loads():
     for cfg in bench["configs"]:
         config = run.load_json(os.path.join(run.ROOT, cfg["file"]))
         assert callable(plugins.load("entries", config["entry"]).drive)
-        assert callable(plugins.load("reference",
-                                     config["reference"]).replay_chunks)
+        ref = plugins.load("reference", config["reference"])
+        assert any(callable(getattr(ref, name, None))
+                   for name in ("replay_chunks", "replay_lanes"))
     for cell in bench["workloads"]:
         mix = run.load_json(os.path.join(run.HERE, "traffic",
                                          cell["traffic"] + ".json"))
-        assert callable(plugins.load("generators", mix["generator"]).make)
+        source = plugins.load("generators", mix["generator"])
+        assert callable(source.make) and callable(source.shrink)
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert callable(plugins.load("metrics", m["name"]).read)
 

@@ -1,6 +1,10 @@
-"""The harness's two optional hooks, on the CPU at a small size: a source
+"""The harness's optional hooks, on the CPU at a small size: a source
 whose lanes idle counts what it fed (``live``), and a reference replays
-lanes that share state in one call (``replay_lanes``).
+lanes that share state in one call (``replay_lanes``); and, at the end, a
+pool over four host devices plugged in with new parts only (an entry's
+``devices`` and ``faults``, a source's ``shrink``, a reference with only
+``replay_lanes``) and ``repro.core.Engine`` given the pool's path, run
+through ``test_selfcheck.outcomes``.
 
 The stand-ins are plugged in through ``plugins.load``'s ``sys.modules``
 lookup:
@@ -81,11 +85,13 @@ class PoolLanes:
         pass
 
 
-def fleet_totals(spec: str, K: int, keys: np.ndarray) -> dict:
-    """``[lanes, steps]`` keys through one fleet: per-lane totals."""
+def fleet_totals(policy, K: int, keys: np.ndarray, **kw) -> dict:
+    """``[lanes, steps]`` keys through one fleet of ``policy`` (a spec or a
+    policy), ``kw`` for ``replay_fleet`` (``mesh``, ``rebalance``):
+    per-lane totals."""
     from repro.fleet import FleetTier, replay_fleet
-    tier = FleetTier(spec, n_lanes=len(keys), budget=K, arbiter="auction")
-    m = replay_fleet(tier, np.ascontiguousarray(keys.T)).metrics
+    tier = FleetTier(policy, n_lanes=len(keys), budget=K, arbiter="auction")
+    m = replay_fleet(tier, np.ascontiguousarray(keys.T), **kw).metrics
     return {f: np.asarray(getattr(m, f), np.float64)
             for f in selfcheck.FIELDS}
 
@@ -125,10 +131,7 @@ def pool_ref_module(hits_off: int = 0):
         tot["hits"] = tot["hits"] + hits_off
         return {lane: {f: v[lane] for f, v in tot.items()} for lane in lanes}
 
-    def replay_chunks(chunks, config, key_bits=32):
-        raise AssertionError("lanes that share state are replayed together")
-
-    mod.replay_lanes, mod.replay_chunks = replay_lanes, replay_chunks
+    mod.replay_lanes = replay_lanes
     return mod
 
 
@@ -269,3 +272,148 @@ def test_live_hook_absent_and_present_read_alike(monkeypatch, traffic,
     assert plain["correct"] == (engine is None)
     if engine is not None:
         assert plain["failed"] > 0
+
+
+# A pool over four chips, plugged in with new parts only: an entry that
+# takes the cell's chips (``drive(devices=)``) and adds a fault of its own
+# (``faults``), a reference with only ``replay_lanes``, and a source with
+# ``live`` and ``shrink``.  The program's engine is extended as the pool's
+# PR would extend it: ``Engine().replay_stream`` takes ``mesh=`` and
+# ``rebalance=`` and then replays the fleet, so the generic faults, planted
+# under it, reach the pool.  It runs through ``test_selfcheck.outcomes``,
+# in a subprocess on four host devices.
+
+POOL4_CONFIG = {"entry": "pool4", "policy": "dac",
+                "policy_params": {"eps": 0.5, "growth": 4, "k_min": 2},
+                "reference": "pool4_ref", "K": 2048, "shards": 4,
+                "rebalance": 16, "ids_per_lane": 1 << 18}
+POOL4_MIX = {"generator": "pool4_lanes", "chunk": [128, 512],
+             "ring": 1 << 15, "rate": 0.15, "mean_session": 400,
+             "id_shift": 13, "warmup_chunks": 4, "check_lanes": 3,
+             "trace_skip": 0, "trace_chunks": 0}
+POOL4_WHATS = ["program", "control", "frozen", "half_batch", "altered",
+               "no_exchange"]
+
+
+def pool4_shrink(config: dict, mix: dict) -> tuple:
+    """8 of the 128 lanes, with their share of the budget."""
+    lanes = 8
+    K = config["K"] * lanes // mix["chunk"][0]
+    return dict(config, K=K), dict(mix, chunk=[lanes, 16], ring=1024)
+
+
+class SpacedPoolLanes(PoolLanes):
+    """``PoolLanes`` with its ids spaced ``2**id_shift`` apart in the int32
+    key space, so that they differ above bit 16 and the control's cut to 16
+    bits folds them onto a few keys; each even id is then moved down onto
+    the multiple of 97 below it, so that the keys whose answers the generic
+    ``altered`` fault reports as misses are half the ids, not one in 97."""
+
+    def __init__(self, config: dict, mix: dict, seed: int):
+        super().__init__(config, mix, seed)
+        spaced = self.keys.astype(np.int64) << mix["id_shift"]
+        spaced -= (spaced % 97) * (self.keys % 2 == 0)
+        self.keys = np.where(self.keys >= 0, spaced, -1).astype(np.int32)
+
+
+def pool_engine():
+    """``repro.core.Engine`` with the pool's path: given ``mesh=``, every
+    chunk fed goes through the fleet of ``policy`` sharded over the mesh,
+    in one call, since the fleet has no streamed form."""
+    from repro.core import Engine, make_policy
+
+    class PoolEngine(Engine):
+        def replay_stream(self, policy, chunks, K, *, mesh=None,
+                          rebalance=256, **kw):
+            if mesh is None:
+                return super().replay_stream(policy, chunks, K, **kw)
+            keys = np.concatenate([np.asarray(c) for c in chunks], axis=1)
+            return selfcheck.result(fleet_totals(
+                make_policy(policy), K, keys, mesh=mesh,
+                rebalance=rebalance))
+
+    return PoolEngine
+
+
+def pool4_drive(config: dict, chunks, engine=None, devices=None):
+    from jax.sharding import Mesh
+
+    from repro.core import Engine
+    engine = Engine() if engine is None else engine
+    spec = plugins.load("entries", "replay_stream").policy_spec(config)
+    return engine.replay_stream(spec, chunks, config["K"],
+                                mesh=Mesh(np.array(devices), ("data",)),
+                                rebalance=config["rebalance"])
+
+
+class NoExchange:
+    """The pool with the re-deal of the budget between chips left out after
+    the first step: a ``rebalance`` period longer than any run."""
+
+    def replay_stream(self, policy, chunks, K, *, rebalance, **kw):
+        from repro.core import Engine
+        return Engine().replay_stream(policy, chunks, K, rebalance=1 << 30,
+                                      **kw)
+
+
+def pool4_replay_lanes(source, lanes, n_chunks, config, key_bits=32):
+    import jax
+    from jax.sharding import Mesh
+    keys = np.stack([np.concatenate([k for k, _ in source.lane_chunks(
+        lane, n_chunks)]) for lane in range(source.lanes)])
+    if key_bits < 32:
+        keys = np.where(keys >= 0, keys & ((1 << key_bits) - 1), keys)
+    spec = plugins.load("entries", "replay_stream").policy_spec(config)
+    mesh = Mesh(np.array(jax.devices()[:config["shards"]]), ("data",))
+    tot = fleet_totals(spec, config["K"], keys, mesh=mesh,
+                       rebalance=config["rebalance"])
+    return {lane: {f: v[lane] for f, v in tot.items()} for lane in lanes}
+
+
+def paced_replay_lanes(clock: Clock, seconds_a_chunk: float = 0.05):
+    """``pool4_replay_lanes`` that moves the fixed-step clock
+    ``seconds_a_chunk`` for each chunk it replays, as a reference's own
+    time would move the host clock."""
+    def replay_lanes(source, lanes, n_chunks, config, key_bits=32):
+        clock.t += seconds_a_chunk * n_chunks
+        return pool4_replay_lanes(source, lanes, n_chunks, config, key_bits)
+    return replay_lanes
+
+
+def install():
+    """The four-chip pool's parts in ``sys.modules``, its path in
+    ``repro.core.Engine``, and the fixed-step clock in the window and the
+    harness (in a subprocess of its own)."""
+    import repro.core
+    clock = Clock()
+    entry = types.ModuleType("chipbench.entries.pool4")
+    entry.drive = pool4_drive
+    entry.faults = lambda config: {"no_exchange": NoExchange()}
+    ref = types.ModuleType("chipbench.reference.pool4_ref")
+    ref.replay_lanes = paced_replay_lanes(clock)
+    source = generator_module("pool4_lanes", SpacedPoolLanes)
+    source.shrink = pool4_shrink
+    for mod in (entry, ref, source):
+        sys.modules[mod.__name__] = mod
+    repro.core.Engine = pool_engine()
+    window.time = clock
+    run.time = clock
+
+
+def test_a_four_chip_pool_needs_no_edit_of_the_harness():
+    from chipbench.tests.test_selfcheck import outcomes
+    outs = outcomes(POOL4_CONFIG, POOL4_MIX, 4, POOL4_WHATS,
+                    plug="chipbench.tests.test_pool_hooks")
+    program = outs["program"]
+    assert program["correct"], program["checks"]
+    assert {k: c["value"] for k, c in program["checks"].items()} == (
+        dict.fromkeys(check.LIMITS, 0.0))
+    assert program["failed"] == 0 and program["device"]["count"] == 4
+    # shrunk by the source's own hook to [8, 16] chunks, idle slots not
+    # counted
+    assert 0 < program["attempted"] < program["window_chunks"] * 8 * 16
+    for what in POOL4_WHATS[1:]:
+        assert not outs[what]["correct"], (what, outs[what]["checks"])
+    # the coupled control is paced by its reference: a window four times
+    # as long holds fewer chunks than the program's
+    assert outs["control"]["window_chunks"] < program["window_chunks"]
